@@ -23,6 +23,8 @@ import sys
 import time
 import traceback
 
+from repro.api.compilation import enable_compile_cache
+
 from . import (
     bench_ablation,
     bench_e2e_load,
@@ -77,4 +79,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

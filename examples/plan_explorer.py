@@ -13,6 +13,7 @@ cross-checked against the template enumerator on the same instance.
 import argparse
 
 from repro.api import ClusterSpec, ModelSpec, Objective, ServeConfig, Session
+from repro.api.compilation import enable_compile_cache
 from repro.configs import ARCH_IDS
 
 SERVE_SEQ = 256  # one request = a seq-256 chunk (benchmarks.common.SERVE_SEQ)
@@ -109,4 +110,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,6 +12,7 @@ a 16-chip cluster, each baseline just one more `session.solve(backend=...)`.
 """
 
 from repro.api import ClusterSpec, ModelSpec, ObsConfig, ServeConfig, Session
+from repro.api.compilation import enable_compile_cache
 from repro.data.requests import poisson_trace
 
 
@@ -66,4 +67,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -38,6 +38,7 @@ import argparse
 import time
 
 from repro.api import ClusterSpec, ModelSpec, ServeConfig, Session
+from repro.api.compilation import enable_compile_cache
 from repro.core import costmodel as cm
 from repro.core.plan import ClusterPlan, PipelinePlan, StagePlan
 from repro.data.requests import bursty_trace, describe, poisson_trace
@@ -186,4 +187,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

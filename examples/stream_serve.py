@@ -36,6 +36,7 @@ from repro.api import (
     Session,
     SourceConfig,
 )
+from repro.api.compilation import enable_compile_cache
 from repro.data.requests import poisson_trace
 from repro.stream import PoissonSource, TraceSource
 
@@ -155,4 +156,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,6 +15,7 @@ sys.path.insert(0, "src")
 import jax
 import jax.numpy as jnp
 
+from repro.api.compilation import enable_compile_cache
 from repro.configs import get_config
 from repro.data.tokens import TokenPipeline
 from repro.models.common import count_params
@@ -74,4 +75,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -444,10 +444,10 @@ class Session:
 
     def deploy(self, mode: str = "sim") -> Session:
         """Materialize the plan: ClusterRuntime + DataPlane; in "real" mode
-        additionally compiled stage executors, a PoolDispatcher, and (for
-        measured feedback / `config.calibrate`) the offline calibration
-        pass.  One deploy per session; plan changes after deploy go through
-        `swap()`."""
+        additionally stage executors warm-compiled at every batch bucket, a
+        PoolDispatcher, and (for measured feedback / `config.calibrate`) the
+        offline calibration pass.  One deploy per session; plan changes
+        after deploy go through `swap()`."""
         self._forbid_closed("deploy")
         if self._state == _DEPLOYED:
             raise LifecycleError(
@@ -470,6 +470,9 @@ class Session:
             from repro.dataplane.plane import calibrate_runtime
 
             self._key = jax.random.PRNGKey(cfg.seed)
+            # compile every batch bucket now, whatever the feedback mode, so
+            # no compilation lands inside the served window
+            self._warm_executors(self._plan)
             executors = self._executors_for(self._plan)
             if self._should_calibrate():
                 calibrate_runtime(runtime, executors, cfg.serve_seq_len,

@@ -633,13 +633,10 @@ class DataPlane:
             self._retry_batch(now, action)
             return
         if self.dispatcher is not None:
+            # an executor error (compile failure, device OOM) propagates:
+            # only the injected faults above are transient by construction
             tokens = self.token_fn(len(action.requests), self.seq_len)
-            try:
-                exec_id = self.dispatcher.submit(action, tokens)
-            except Exception:  # noqa: BLE001 — executor died: return capacity
-                reservation.cancel(pr)
-                self._retry_batch(now, action)
-                return
+            exec_id = self.dispatcher.submit(action, tokens)
         # telemetry only for batches that actually execute
         depth_after = self.batcher.pending(action.pipeline.model_name)
         self.tel.dispatches.append(DispatchRecord(
@@ -680,12 +677,12 @@ class DataPlane:
         Each failed request with budget left re-enters the EDF queue through
         the normal admission path — the next scheduling round re-probes
         EVERY pool, so the retry is hedged across pool members rather than
-        pinned to the member that just failed (or is straggling).  Without
-        an attached injector the budget is 0, reproducing the legacy
+        pinned to the member that just failed (or is straggling).  Only an
+        injected fault lands here; a budget of 0 reproduces the legacy
         fail-the-batch behaviour exactly.  Requests out of budget drop with
         the explicit ``exec_failure`` cause — never silently."""
         self.tel.exec_failures += 1  # per BATCH; drops are per request
-        budget = self.faults.max_retries if self.faults is not None else 0
+        budget = self.faults.max_retries
         readmit: list[Request] = []
         exhausted: list[Request] = []
         for r in action.requests:

@@ -1,4 +1,10 @@
-"""Public jit'd wrappers for boundary quantization."""
+"""Public jit'd wrappers for boundary quantization.
+
+The Pallas kernels run compiled on every backend but the CPU, which has no
+Mosaic lowering and takes interpret mode.  The choice is made per lowering
+platform (`lax.platform_dependent`), not from `jax.default_backend()`, so a
+program compiled for a TPU from a CPU-only process still gets the kernel.
+"""
 
 from __future__ import annotations
 
@@ -10,10 +16,6 @@ import jax.numpy as jnp
 from . import kernel
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _divisor_block(rows: int, target: int = 256) -> int:
     for b in range(min(target, rows), 0, -1):
         if rows % b == 0:
@@ -21,14 +23,23 @@ def _divisor_block(rows: int, target: int = 256) -> int:
     return 1
 
 
+def _per_platform(fn, *args):
+    """`fn(*args, interpret=...)`: interpret mode on CPU only."""
+    return jax.lax.platform_dependent(
+        *args,
+        cpu=partial(fn, interpret=True),
+        default=partial(fn, interpret=False),
+    )
+
+
 @jax.jit
 def quantize(x):
     """x: (..., D) -> (int8 (..., D), f32 scales (..., 1))."""
     shape = x.shape
     rows = max(1, x.size // shape[-1])
-    q, s = kernel.quantize(
-        x.reshape(-1, shape[-1]), block_rows=_divisor_block(rows),
-        interpret=not _on_tpu(),
+    q, s = _per_platform(
+        partial(kernel.quantize, block_rows=_divisor_block(rows)),
+        x.reshape(-1, shape[-1]),
     )
     return q.reshape(shape), s.reshape(shape[:-1] + (1,))
 
@@ -37,8 +48,9 @@ def quantize(x):
 def dequantize(q, scale, dtype=jnp.bfloat16):
     shape = q.shape
     rows = max(1, q.size // shape[-1])
-    out = kernel.dequantize(
-        q.reshape(-1, shape[-1]), scale.reshape(-1, 1), dtype=dtype,
-        block_rows=_divisor_block(rows), interpret=not _on_tpu(),
+    out = _per_platform(
+        partial(kernel.dequantize, dtype=dtype,
+                block_rows=_divisor_block(rows)),
+        q.reshape(-1, shape[-1]), scale.reshape(-1, 1),
     )
     return out.reshape(shape)

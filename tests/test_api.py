@@ -346,6 +346,27 @@ def test_enable_replanning_wires_recalibration_and_warm_factory(
         assert disp.executors
 
 
+def test_real_deploy_leaves_no_compilation_inside_run(real_session_setup):
+    """deploy(mode="real") warms every batch bucket even with planned
+    feedback (no calibration pass), so serving compiles nothing."""
+    from repro.api.compilation import count_compiles
+    from repro.data.requests import poisson_trace
+
+    fixture_session, plan_a, _, prof = real_session_setup
+    assert fixture_session.config.feedback == "planned"
+    with Session.from_config(fixture_session.config,
+                             store=fixture_session.store) as session:
+        session.use_plan(plan_a)
+        session.deploy(mode="real")
+        rate = plan_a.throughput * 0.5
+        trace = poisson_trace(rate, 16 / rate, prof.slo_s, "stablelm-3b",
+                              seed=9)
+        with count_compiles() as compiles:
+            report = session.run(trace)
+        assert report.telemetry.dispatches  # batches really executed
+        assert compiles.n == 0
+
+
 def test_swap_back_reuses_cached_executors(real_session_setup):
     session, plan_a, plan_b, prof = real_session_setup
     # both partitionings are compiled by now: swapping back must compile

@@ -24,6 +24,7 @@ from repro.core.runtime import build_runtime
 from repro.core.types import ClusterSpec, replace
 from repro.data.requests import multi_model_trace
 from repro.dataplane import DataPlane
+from repro.dataplane.dispatcher import PoolDispatcher
 from repro.faults import (
     FAULT_KINDS,
     FailureInjector,
@@ -33,6 +34,7 @@ from repro.faults import (
     FaultSchedule,
 )
 from repro.obs import ObsConfig, Observer
+from repro.serving.engine import StageExecutor
 
 CLUSTER = ClusterSpec(counts={"tpu-hi": 2, "tpu-lo": 4})
 
@@ -219,6 +221,41 @@ def test_exec_fault_budget_zero_reproduces_legacy_drop():
     assert not dups and not missing
 
 
+def _failing_dispatcher(plan):
+    """A real PoolDispatcher whose stage programs fail the way a compile
+    error or a device out-of-memory does: the executor call raises."""
+    def broken(params, carry):
+        raise RuntimeError("stage program failed to compile")
+
+    ex = StageExecutor(stage_fn=broken, params={})
+    return PoolDispatcher({pid: [ex] * len(pp.stages)
+                           for pid, pp in enumerate(plan.pipelines)})
+
+
+def test_executor_error_propagates_while_injected_faults_retry():
+    profs, _, _, plan_a, _ = _two_plan_setup()
+    trace = _trace(profs, plan_a, 2.0, load=0.5, seed=4)
+
+    # an executor error is not a transient fault: serve raises it instead
+    # of turning it into retries and drops
+    dp = DataPlane(build_runtime(plan_a, profs),
+                   dispatcher=_failing_dispatcher(plan_a))
+    with pytest.raises(RuntimeError, match="failed to compile"):
+        dp.serve(trace)
+    assert dp.tel.exec_failures == 0 and dp.tel.retries == 0
+
+    # injected faults fire before the dispatcher is reached and keep their
+    # bounded retry; the first real submit then surfaces the error
+    dp = DataPlane(build_runtime(plan_a, profs),
+                   dispatcher=_failing_dispatcher(plan_a))
+    inj = FaultInjector(FaultSchedule([FaultEvent(0.0, "exec_fault", count=2)]),
+                        max_retries=2).attach(dp)
+    inj.poll(0.0)  # arm both faults before the first dispatch
+    with pytest.raises(RuntimeError, match="failed to compile"):
+        dp.serve(trace)
+    assert dp.tel.exec_failures == 2 and dp.tel.retries >= 1
+
+
 def test_chip_slowdown_stretches_stage_durations():
     profs, _, _, plan_a, _ = _two_plan_setup()
     trace = _trace(profs, plan_a, 2.0, load=0.4, seed=2)
@@ -327,7 +364,7 @@ def test_node_loss_with_replan_loop_installs_mandatory_plan():
 @settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 10_000),
        fault_seed=st.integers(0, 10_000),
-       swap_offsets=st.lists(st.floats(0.4, 3.2), 0, 2),
+       swap_offsets=st.lists(st.floats(0.4, 3.2), min_size=0, max_size=2),
        with_loss=st.booleans())
 def test_property_every_request_resolves_exactly_once(seed, fault_seed,
                                                       swap_offsets,
