@@ -8,8 +8,6 @@ Prints ``name,us_per_call,derived`` CSV lines.  ``--quick`` shrinks sweeps.
   bench_ablation    Fig. 10  reservation vs reactive data plane
   bench_sensitivity Fig. 13  SLO scale / class ratio / margin sweeps
   bench_sched       §5.4     scheduler hot-path old-vs-new (BENCH_sched.json)
-  bench_kernels     —        kernel micro-benchmarks
-  roofline          §Roofline  table from results/dryrun_*.jsonl
 
 ``--full`` additionally runs the paper-scale (HC1-L, 3-model) drift and
 oscillation re-planning scenarios in bench_e2e_load.
@@ -28,12 +26,10 @@ from repro.api.compilation import enable_compile_cache
 from . import (
     bench_ablation,
     bench_e2e_load,
-    bench_kernels,
     bench_milp,
     bench_sched,
     bench_sensitivity,
     bench_utilization,
-    roofline,
 )
 
 BENCHES = {
@@ -43,8 +39,6 @@ BENCHES = {
     "ablation": bench_ablation.main,
     "sensitivity": bench_sensitivity.main,
     "sched": bench_sched.main,
-    "kernels": bench_kernels.main,
-    "roofline": roofline.main,
 }
 
 

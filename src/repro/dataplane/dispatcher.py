@@ -26,7 +26,7 @@ import jax
 
 from repro.core.runtime import ClusterRuntime
 from repro.core.scheduler import Dispatch
-
+from repro.obs.host import SUBMIT, WAIT, span
 from repro.serving.engine import StageExecutor
 
 
@@ -40,6 +40,8 @@ class _InFlight:
     submit_wall: float
     ready_wall: list  # per-stage wall timestamp once observed ready
     epoch: int = 0  # plan epoch at submit time (telemetry bucketing)
+    batch_id: int | None = None  # the data plane's id of the batch
+    dispatch_t_s: float | None = None  # its dispatch time, virtual clock
 
 
 @dataclass
@@ -56,6 +58,10 @@ class CompletedBatch:
     # pipeline ids restart at 0 per epoch — telemetry keys stage walls by
     # (epoch, pipeline, stage), so the batch must carry its own epoch
     epoch: int = 0
+    # the data plane's batch id and virtual dispatch time, so the batch's
+    # "batch.wall" journal event joins its "batch.dispatch"/"exec.stage"
+    batch_id: int | None = None
+    dispatch_t_s: float | None = None
 
     @property
     def total_wall_s(self) -> float:
@@ -76,8 +82,8 @@ class PoolDispatcher:
         # still buckets measurements under the epoch that submitted them
         self.current_epoch = 0
         # optional repro.obs.Observer (set by DataPlane._install_runtime):
-        # every retired batch's wall measurements flow to it as a
-        # "batch.wall" journal event — the wall-clock side of the trace
+        # every retired batch's wall durations flow to it as a "batch.wall"
+        # journal event, under the plane's batch id and dispatch time
         self.obs = None
         self.max_inflight = max(1, max_inflight)
         self._inflight: list[_InFlight] = []
@@ -99,23 +105,30 @@ class PoolDispatcher:
         return cls(executors_by_pipeline, vdev_map, max_inflight)
 
     # ----------------------------------------------------------- submission
-    def submit(self, dispatch: Dispatch, tokens) -> int:
-        """Enqueue every stage of a scheduled batch; non-blocking."""
+    def submit(self, dispatch: Dispatch, tokens, batch_id: int | None = None,
+               t_s: float | None = None) -> int:
+        """Enqueue every stage of a scheduled batch; non-blocking.
+        `batch_id` and `t_s` are the data plane's id and virtual dispatch
+        time of the batch, carried to its completed record."""
         members = [self.vdev_map.get(v.vdev_id, (si, 0))[1]
                    for si, v in enumerate(dispatch.probe_result.path)]
-        return self.submit_chain(dispatch.pipeline.pipeline_id, tokens, members)
+        return self.submit_chain(dispatch.pipeline.pipeline_id, tokens, members,
+                                 batch_id, t_s)
 
-    def submit_chain(self, pipeline_id: int, tokens, members=None) -> int:
+    def submit_chain(self, pipeline_id: int, tokens, members=None,
+                     batch_id: int | None = None,
+                     t_s: float | None = None) -> int:
         execs = self.executors[pipeline_id]
         members = members if members is not None else [0] * len(execs)
         t0 = time.perf_counter()
         carry = tokens
         outputs = []
-        for si, ex in enumerate(execs):
-            if si > 0:
-                carry = ex.transfer(carry)
-            carry = ex(carry)  # async: enqueues and returns immediately
-            outputs.append(carry)
+        with span(SUBMIT, pipeline=pipeline_id, batch=int(tokens.shape[0])):
+            for si, ex in enumerate(execs):
+                if si > 0:
+                    carry = ex.transfer(carry)
+                carry = ex(carry)  # async: enqueues and returns immediately
+                outputs.append(carry)
         job = _InFlight(
             job_id=next(self._job_ids),
             pipeline_id=pipeline_id,
@@ -125,6 +138,8 @@ class PoolDispatcher:
             submit_wall=t0,
             ready_wall=[None] * len(outputs),
             epoch=self.current_epoch,
+            batch_id=batch_id,
+            dispatch_t_s=t_s,
         )
         self._inflight.append(job)
         self.submitted += 1
@@ -201,7 +216,8 @@ class PoolDispatcher:
     def _measure_through(self, job: _InFlight, stage_idx: int) -> None:
         for k in range(stage_idx + 1):
             if job.ready_wall[k] is None:
-                jax.block_until_ready(job.outputs[k])
+                with span(WAIT, stage=k):
+                    jax.block_until_ready(job.outputs[k])
                 job.ready_wall[k] = time.perf_counter()
 
     def _retire(self, job: _InFlight) -> None:
@@ -221,6 +237,8 @@ class PoolDispatcher:
             submit_wall=job.submit_wall,
             done_wall=job.ready_wall[-1],
             epoch=job.epoch,
+            batch_id=job.batch_id,
+            dispatch_t_s=job.dispatch_t_s,
         )
         self._completed.append(done)
         self._done_by_id[job.job_id] = done
@@ -238,6 +256,13 @@ class FeedbackController:
     mapped into virtual time through it; persistent drift from the planned
     latency is folded into `StageRuntime.lat_scale` with a multiplicative
     EWMA, so future probe() calls price the stage at its observed speed.
+
+    Per (pipeline, stage) it counts what it was fed: `measured_wall_s`, the
+    sum of the walls `observe` received, `observed`, how many, and
+    `scale_peak`, the highest `lat_scale` `observe` set since `reset_peaks`.
+    A reader takes deltas of the first two over a window; their sum over a
+    stage against the stage program's device time shows how far the walls
+    drift from the chip's own time.
     """
 
     def __init__(self, runtime: ClusterRuntime, alpha: float = 0.4,
@@ -249,14 +274,29 @@ class FeedbackController:
         self.scale_bounds = scale_bounds
         self._by_id = {p.pipeline_id: p for p in runtime.pipelines}
         self.calib: dict[tuple[int, int], float] = {}
-        self.last_ratio: dict[tuple[int, int], float] = {}
-        self.observations = 0
+        self.measured_wall_s: dict[tuple[int, int], float] = {}
+        self.observed: dict[tuple[int, int], int] = {}
+        self.scale_peak: dict[tuple[int, int], float] = {}
+
+    @property
+    def observations(self) -> int:
+        return sum(self.observed.values())
+
+    def reset_peaks(self) -> None:
+        """Start a new peak window: each stage's `scale_peak` becomes its
+        current `lat_scale`."""
+        for p in self.runtime.pipelines:
+            for si, stage in enumerate(p.stages):
+                self.scale_peak[(p.pipeline_id, si)] = stage.lat_scale
 
     def observe(self, pipeline_id: int, stage_idx: int,
                 planned_s: float, measured_wall_s: float) -> float:
         """Fold one measured stage execution back in; returns the measured
         duration expressed in virtual seconds."""
         key = (pipeline_id, stage_idx)
+        self.measured_wall_s[key] = (self.measured_wall_s.get(key, 0.0)
+                                     + measured_wall_s)
+        self.observed[key] = self.observed.get(key, 0) + 1
         measured_wall_s = max(measured_wall_s, 1e-12)
         planned_s = max(planned_s, 1e-12)
         cal = self.calib.get(key)
@@ -264,10 +304,10 @@ class FeedbackController:
             cal = self.calib[key] = measured_wall_s / planned_s
         virtual = measured_wall_s / cal
         ratio = virtual / planned_s
-        self.last_ratio[key] = ratio
-        self.observations += 1
         if self.adapt_latency:
             stage = self._by_id[pipeline_id].stages[stage_idx]
             lo, hi = self.scale_bounds
             stage.lat_scale = min(hi, max(lo, stage.lat_scale * ratio ** self.alpha))
+            self.scale_peak[key] = max(self.scale_peak.get(key, stage.lat_scale),
+                                       stage.lat_scale)
         return virtual

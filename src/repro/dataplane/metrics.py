@@ -94,7 +94,6 @@ class Telemetry:
     # measured wall seconds per (epoch, pipeline_id, stage_idx), real
     # execution only (pipeline ids restart at 0 after each plan swap)
     stage_wall_s: dict = field(default_factory=dict)
-    batch_wall_s: list[float] = field(default_factory=list)
     utilization: dict = field(default_factory=dict)
     feedback_scales: dict = field(default_factory=dict)
 

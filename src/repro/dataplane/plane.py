@@ -33,6 +33,7 @@ from repro.core.reservation import PipelineRuntime
 from repro.core.runtime import ClusterRuntime, build_runtime
 from repro.core.scheduler import Dispatch, Drop, WaitUntil
 from repro.core.types import ModelProfile, Request, RequestOutcome
+from repro.obs.host import SCHEDULE, TOKENS, span
 from repro.obs.observer import (
     OP_ARRIVE,
     OP_COMPLETE,
@@ -340,7 +341,8 @@ class DataPlane:
 
     # --------------------------------------------------------------- scheduler
     def _run_scheduler(self, model: str, now: float) -> None:
-        expired, actions = self.batcher.plan(model, now)
+        with span(SCHEDULE):
+            expired, actions = self.batcher.plan(model, now)
         for r in expired:
             self._drop(r, now, "expired")
         for action in actions:
@@ -632,11 +634,13 @@ class DataPlane:
             reservation.cancel(pr)
             self._retry_batch(now, action)
             return
+        job_id = next(self.job_ids)
         if self.dispatcher is not None:
             # an executor error (compile failure, device OOM) propagates:
             # only the injected faults above are transient by construction
-            tokens = self.token_fn(len(action.requests), self.seq_len)
-            exec_id = self.dispatcher.submit(action, tokens)
+            with span(TOKENS):
+                tokens = self.token_fn(len(action.requests), self.seq_len)
+            exec_id = self.dispatcher.submit(action, tokens, job_id, now)
         # telemetry only for batches that actually execute
         depth_after = self.batcher.pending(action.pipeline.model_name)
         self.tel.dispatches.append(DispatchRecord(
@@ -650,7 +654,7 @@ class DataPlane:
         ))
         self.tel.queue_delay_s.extend(now - r.arrival_s for r in action.requests)
         job = _Job(
-            job_id=next(self.job_ids),
+            job_id=job_id,
             pipeline_id=action.pipeline.pipeline_id,
             requests=action.requests,
             probe=pr,
@@ -975,7 +979,6 @@ class DataPlane:
     def _harvest_dispatcher(self, disp: PoolDispatcher) -> None:
         disp.drain_all()
         for c in disp.take_completed():
-            self.tel.batch_wall_s.append(c.total_wall_s)
             for si, w in enumerate(c.stage_wall_s):
                 # keyed by the epoch the batch was SUBMITTED under (stamped
                 # by the dispatcher — _install_runtime keeps current_epoch

@@ -107,8 +107,9 @@ class Observer:
         self.push((OP_COMPLETE, t, req, batch_id))
 
     def on_batch_wall(self, done) -> None:
-        """Wall-clock side of a real-execution batch (`CompletedBatch`) —
-        recorded on the *wall* axis, complementing the virtual-clock spans."""
+        """Wall-clock side of a real-execution batch (`CompletedBatch`):
+        its measured wall durations, under the plane's batch id and at its
+        dispatch time on the virtual clock, beside its virtual spans."""
         self.push((OP_BATCH_WALL, done))
 
     # ------------------------------------------------- control-plane hooks
@@ -281,8 +282,8 @@ class Observer:
                             "req_id": req.req_id, "cause": cause})
             else:  # OP_BATCH_WALL
                 done = rec[1]
-                append({"t_s": done.submit_wall, "kind": BATCH_WALL,
-                        "batch_id": done.job_id, "epoch": done.epoch,
+                append({"t_s": done.dispatch_t_s, "kind": BATCH_WALL,
+                        "batch_id": done.batch_id, "epoch": done.epoch,
                         "pipeline_id": done.pipeline_id,
                         "wall_s": done.total_wall_s,
                         "stage_wall_s": [float(x)

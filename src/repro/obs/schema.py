@@ -92,7 +92,9 @@ SCHEMA: dict[str, EventSchema] = {
     # ul/dl are [accel_class, host_id] NIC endpoints
     EXEC_XFER: EventSchema(
         EXEC_XFER, ("batch_id", "epoch", "ul", "dl", "start_s", "dur_s")),
-    # real execution only; t_s is the *wall* submit time
+    # real execution only; t_s is the batch's dispatch time (virtual, as
+    # everywhere) and batch_id the plane's, as in batch.dispatch; wall_s and
+    # stage_wall_s are measured wall durations in seconds
     BATCH_WALL: EventSchema(
         BATCH_WALL,
         ("batch_id", "epoch", "pipeline_id", "wall_s", "stage_wall_s")),

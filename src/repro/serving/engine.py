@@ -42,7 +42,8 @@ def split_stages(cfg: ModelConfig, block_ranges: list[tuple[int, int]],
 
     `layer_block_map[b] = (layer_start, layer_end)` for each pre-partitioned
     block b (0 = embed, last = head).  Each stage closure takes (params,
-    carry) where carry is tokens for stage 0 and hidden states afterwards.
+    carry) where carry is tokens for stage 0 and hidden states afterwards,
+    and is named `stage_b{i}_{j}` for its block range [i, j).
     """
     model = build_model(cfg)
     n_blocks = len(layer_block_map)
@@ -73,6 +74,9 @@ def split_stages(cfg: ModelConfig, block_ranges: list[tuple[int, int]],
                 return tfm.unembed(cfg, rules, params, x)
             return x
 
+        # the program's name in compiled modules and device traces:
+        # jit_stage_b{i}_{j}, one per block range
+        stage.__name__ = stage.__qualname__ = f"stage_b{i}_{j}"
         return stage
 
     return model, [make_stage(i, j) for i, j in block_ranges]

@@ -212,6 +212,35 @@ def test_feedback_noise_does_not_drift():
     assert 0.8 < stage.lat_scale < 1.25
 
 
+def test_feedback_counters_sum_walls_and_hold_the_peak():
+    """Per stage, the controller sums the walls it was fed, counts them, and
+    keeps the highest scale it set since `reset_peaks`."""
+    rt = _runtime()
+    fb = FeedbackController(rt, alpha=0.4, adapt_latency=True)
+    p = rt.pipelines[0]
+    stage = p.stages[0]
+    key = (p.pipeline_id, 0)
+    planned = stage.latency(4)
+    walls = [planned, 3.0 * planned, 0.5 * planned, 0.5 * planned]
+    scales = []
+    for w in walls:
+        fb.observe(p.pipeline_id, 0, planned, w)
+        scales.append(stage.lat_scale)
+    assert fb.measured_wall_s[key] == pytest.approx(sum(walls))
+    assert fb.observed[key] == len(walls) == fb.observations
+    assert fb.scale_peak[key] == max(scales) > stage.lat_scale
+    fb.reset_peaks()
+    # every stage starts the new window at its current scale, observed or not
+    assert fb.scale_peak == {(q.pipeline_id, si): s.lat_scale
+                             for q in rt.pipelines
+                             for si, s in enumerate(q.stages)}
+    fb.observe(p.pipeline_id, 0, planned, 0.25 * planned)  # the scale falls
+    assert stage.lat_scale < scales[-1] == fb.scale_peak[key]
+    fb.observe(p.pipeline_id, 0, planned, 8.0 * planned)  # and rises again
+    assert fb.scale_peak[key] == stage.lat_scale > scales[-1]
+    assert fb.observed[key] == len(walls) + 2
+
+
 # ---------------------------------------------------------------------------
 # Real JAX execution: overlapped pool dispatch on a 2-stage pooled pipeline
 # ---------------------------------------------------------------------------
@@ -372,3 +401,52 @@ def test_transfer_skips_integer_and_same_device(real_pipeline):
     # single host: sender and receiver share the device -> identity, no
     # quantize->dequantize round trip
     assert ex.transfer(h) is h
+
+
+def _trace_reduce():
+    """The chip benchmark's trace reader (benchmarks/chip/trace_reduce.py)."""
+    import importlib.util
+    from pathlib import Path
+
+    path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "trace_reduce.py")
+    spec = importlib.util.spec_from_file_location("trace_reduce", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_host_spans_on_the_profiler_clock(real_pipeline, tmp_path):
+    """Measured feedback served under the JAX profiler: the plane's host
+    spans are on the profiler's host plane, flat (none inside another), one
+    `ppipe.submit` and one `ppipe.tokens` per dispatch, and at most one
+    `ppipe.wait` per stage of a dispatch."""
+    import jax
+
+    from repro.core.runtime import build_runtime as _br
+    from repro.dataplane import PoolDispatcher, calibrate_runtime
+    from repro.obs.host import SCHEDULE, SPANS, SUBMIT, TOKENS, WAIT
+
+    cfg, prof, plan, executors, seq = real_pipeline
+    rt = _br(plan, {cfg.name: prof})
+    calibrate_runtime(rt, executors, seq)
+    p0 = rt.pipelines[0]
+    e2e = sum(s.latency(1) for s in p0.stages)
+    thr = min(len(s.vdevs) * p0.unified_batch / s.latency(p0.unified_batch)
+              for s in p0.stages)
+    trace = poisson_trace(thr * 0.4, 16 / (thr * 0.4), e2e * 8, cfg.name,
+                          seed=11)
+    dp = DataPlane(rt, dispatcher=PoolDispatcher.from_runtime(rt, executors),
+                   feedback="measured", seq_len=seq)
+    with jax.profiler.trace(str(tmp_path)):
+        tel = dp.serve(trace)
+    host = _trace_reduce().read_xplane(tmp_path)["host"]
+    spans = sorted((s, s + d, name) for name, s, d in host if name in SPANS)
+    counts = {name: sum(1 for *_, n in spans if n == name) for name in SPANS}
+    n = len(tel.dispatches)
+    assert n > 0 and dp.fb.observations == n * len(p0.stages)
+    assert counts[SUBMIT] == counts[TOKENS] == n
+    assert 0 < counts[WAIT] <= n * len(p0.stages)
+    assert counts[SCHEDULE] >= n
+    for (_, end, _), (start, _, _) in zip(spans, spans[1:]):
+        assert start >= end  # flat: no ppipe span encloses another

@@ -15,6 +15,7 @@ import pytest
 # runtimes/traces from the scheduler decision-equivalence suite
 import test_epoch_lifecycle as lifecycle
 import test_sched_equivalence as equiv
+from test_dataplane import real_pipeline  # noqa: F401  (fixture)
 from repro.api import LifecycleError
 from repro.core.runtime import build_runtime
 from repro.dataplane import DataPlane
@@ -80,6 +81,24 @@ def test_observer_is_decision_identical_on_random_runtimes(seed):
         len(tel_on.dispatches)
 
 
+def test_off_path_is_decision_identical_under_the_profiler(tmp_path):
+    """With observability off, an active JAX profiler (which makes the
+    plane's host spans record) changes no decision."""
+    import jax
+
+    _, tel_off, trace = _swap_scenario(None)
+    with jax.profiler.trace(str(tmp_path)):
+        _, tel_on, _ = _swap_scenario(None)
+    assert _outcomes(tel_on) == _outcomes(tel_off)
+    assert len(tel_on.outcomes) == len(trace)
+    assert tel_on.plan_swaps == tel_off.plan_swaps
+    assert [(d.t_s, d.pipeline_id, d.batch_size, d.epoch)
+            for d in tel_on.dispatches] == \
+           [(d.t_s, d.pipeline_id, d.batch_size, d.epoch)
+            for d in tel_off.dispatches]
+    assert tel_on.scheduler == tel_off.scheduler
+
+
 # ---------------------------------------------------------------------------
 # Journal contents and scheduler-stats surfacing
 # ---------------------------------------------------------------------------
@@ -105,6 +124,48 @@ def test_journal_records_swaps_and_exec_events():
                  for e in obs.journal.select(kind="batch.dispatch")}
     for ev in obs.journal.select(kind="req.complete"):
         assert ev["batch_id"] in batch_ids
+
+
+def test_batch_wall_joins_dispatch_on_the_virtual_clock(real_pipeline):
+    """Each real batch's `batch.wall` event carries the plane's batch id and
+    its virtual dispatch time, so it joins `batch.dispatch` and
+    `exec.stage` — also after a swap to a fresh dispatcher, whose own job
+    ids restart at 0."""
+    from repro.core.runtime import build_runtime
+    from repro.data.requests import poisson_trace
+    from repro.dataplane import PoolDispatcher
+
+    cfg, prof, plan, executors, seq = real_pipeline
+    rt = build_runtime(plan, {cfg.name: prof})
+    thr = plan.throughput
+    trace = poisson_trace(thr * 0.5, 24 / (thr * 0.5), prof.slo_s, cfg.name,
+                          seed=9)
+    obs = Observer(ObsConfig(level="trace"))
+    dp = DataPlane(rt, dispatcher=PoolDispatcher.from_runtime(rt, executors),
+                   seq_len=seq, observer=obs)
+    mid = trace[len(trace) // 2].arrival_s
+
+    def swap_once(req, t):
+        if dp.epoch == 0 and t > mid:
+            dp.swap_plan(plan, {cfg.name: prof}, now=t,
+                         dispatcher_factory=lambda new_rt: PoolDispatcher
+                         .from_runtime(new_rt, executors))
+
+    dp.arrival_hooks.append(swap_once)
+    dp.serve(trace)
+    assert dp.epoch == 1
+    dispatched = {e["batch_id"]: e
+                  for e in obs.journal.select(kind="batch.dispatch")}
+    walls = obs.journal.select(kind="batch.wall")
+    assert sorted(e["batch_id"] for e in walls) == sorted(dispatched)
+    staged = {e["batch_id"] for e in obs.journal.select(kind="exec.stage")}
+    assert staged == set(dispatched)
+    for e in walls:
+        d = dispatched[e["batch_id"]]
+        assert (e["t_s"], e["epoch"], e["pipeline_id"]) == \
+            (d["t_s"], d["epoch"], d["pipeline_id"])
+        assert len(e["stage_wall_s"]) == 2 and e["wall_s"] >= 0.0
+    assert {e["epoch"] for e in walls} == {0, 1}
 
 
 def test_scheduler_stats_surfaced_in_snapshot():

@@ -38,6 +38,20 @@ def test_stage_split_matches_full_forward():
                                np.asarray(full, np.float32), atol=2e-2, rtol=2e-2)
 
 
+def test_stage_functions_named_by_block_range():
+    """Each stage program is named for its block range, so its compiled
+    module (and its device-trace events) read `jit_stage_b{i}_{j}`."""
+    cfg = get_config("stablelm-3b").reduced(n_layers=4)
+    lbm = _layer_block_map(cfg.n_layers, 5)
+    n = len(lbm)
+    model, stages = split_stages(cfg, [(0, 2), (2, n)], lbm)
+    assert [f.__name__ for f in stages] == ["stage_b0_2", f"stage_b2_{n}"]
+    params = jax.eval_shape(model.init, KEY)
+    tokens = jax.ShapeDtypeStruct((2, 12), jnp.int32)
+    text = jax.jit(stages[0]).lower(params, tokens).as_text()
+    assert text.startswith("module @jit_stage_b0_2 ")
+
+
 def test_engine_runs_pipeline_plan():
     cfg = get_config("stablelm-3b").reduced(n_layers=4)
     lbm = _layer_block_map(cfg.n_layers, 5)
