@@ -44,6 +44,11 @@ def split_stages(cfg: ModelConfig, block_ranges: list[tuple[int, int]],
     block b (0 = embed, last = head).  Each stage closure takes (params,
     carry) where carry is tokens for stage 0 and hidden states afterwards,
     and is named `stage_b{i}_{j}` for its block range [i, j).
+
+    A stage scans the indices of its layer range and indexes each stacked
+    leaf of `params["layers"]` in the scan body, so the range is read in
+    place from the bound tree: a slice `a[lstart:lend]` fed to the scan
+    would be copied out of the parameter buffers on every call.
     """
     model = build_model(cfg)
     n_blocks = len(layer_block_map)
@@ -62,13 +67,16 @@ def split_stages(cfg: ModelConfig, block_ranges: list[tuple[int, int]],
                 lstart, lend = lo, hi
             B, S, _ = x.shape
             positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-            lslice = jax.tree.map(lambda a: a[lstart:lend], params["layers"])
 
-            def body(x, lp):
+            def body(x, layer):
+                lp = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, layer,
+                                                           keepdims=False),
+                    params["layers"])
                 x, _ = tfm.layer_full(cfg, rules, lp, x, positions)
                 return x, None
 
-            x, _ = jax.lax.scan(body, x, lslice)
+            x, _ = jax.lax.scan(body, x, jnp.arange(lstart, lend))
             if j == n_blocks:
                 x = rms_norm(x, params["final_norm"], cfg.norm_eps)
                 return tfm.unembed(cfg, rules, params, x)

@@ -12,6 +12,7 @@ this file must not touch it.  Keep every described-TPU compile in this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,3 +101,64 @@ def test_full_width_two_stage_stablelm_fits_one_chip(one_chip):
     assert resident < V5E_HBM_BYTES, [
         (m.argument_size_in_bytes, m.temp_size_in_bytes,
          m.output_size_in_bytes) for m in mems]
+
+
+def _entry_slices_of_layer_params(hlo: str) -> list[str]:
+    """The slice instructions (`slice`, its asynchronous `slice-start`, or
+    `dynamic-slice`) of the compiled entry computation whose operand is a
+    `params['layers']` leaf, directly or through copies (a small leaf may
+    first be copied to on-chip memory)."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    instr = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = .*? ([a-z][a-z0-9\-]*)"
+                       r"\(([^()]*)\)")
+    layer_bufs, slices = set(), []
+    for line in entry.splitlines():
+        m = instr.match(line)
+        if m is None:
+            continue
+        name, op, operands = m[1], m[2], re.findall(r"%([\w.\-]+)", m[3])
+        if op == "parameter" and "params[\\'layers\\']" in line:
+            layer_bufs.add(name)
+        elif op in ("copy", "copy-start", "copy-done", "bitcast") and (
+                operands and operands[0] in layer_bufs):
+            layer_bufs.add(name)
+        elif op in ("slice", "slice-start", "dynamic-slice") and (
+                operands and operands[0] in layer_bufs):
+            slices.append(line.strip()[:160])
+    return slices
+
+
+def test_stage_programs_read_layer_range_in_place(one_chip):
+    """stablelm-3b at its published width, cut like the benchmark's cell
+    (stage 0 = embedding and layers 0-5, stage 1 = layers 5-32 and the
+    head), compiled at batch 8 x seq 256: neither stage copies its layer
+    range out of the bound parameters before its scan, so no entry `slice`
+    reads a layer leaf and the temporaries stay below one layer's weights
+    (a range copied before the scan takes its whole size in temporaries)."""
+    seq, batch = 256, 8
+    cfg = get_config("stablelm-3b")
+    prof = profile_model(ModelSpec(arch="stablelm-3b", seq_len=seq,
+                                   n_blocks=10),
+                         ClusterSpec(counts={"tpu-lo": 1, "tpu-hi": 1}))
+    lbm = layer_block_map_from_profile(prof, cfg.n_layers)
+    assert (lbm[0][0], lbm[1][1], lbm[2][0], lbm[-1][1]) == (0, 5, 5, 32)
+    model, stages = split_stages(cfg, [(0, 2), (2, prof.n_blocks)], lbm)
+
+    def on_chip(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip,
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    layer_bytes = sum(a.size // a.shape[0] * a.dtype.itemsize
+                      for a in jax.tree.leaves(params["layers"]))
+    assert layer_bytes == 79_303_680 * 2
+    carries = [on_chip(jax.ShapeDtypeStruct((batch, seq), jnp.int32)),
+               on_chip(jax.ShapeDtypeStruct((batch, seq, cfg.d_model),
+                                            jnp.bfloat16))]
+    for stage, carry in zip(stages, carries):
+        compiled = jax.jit(stage).lower(params, carry).compile()
+        assert _entry_slices_of_layer_params(compiled.as_text()) == [], \
+            stage.__name__
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < layer_bytes, (stage.__name__, temp)

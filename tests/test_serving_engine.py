@@ -3,9 +3,12 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.core.plan import PipelinePlan, StagePlan
+from repro.models import transformer as tfm
+from repro.models.common import NO_SHARDING
 from repro.serving.engine import build_engine, split_stages
 
 KEY = jax.random.PRNGKey(3)
@@ -24,18 +27,54 @@ def _layer_block_map(n_layers, n_blocks):
     return blocks
 
 
-def test_stage_split_matches_full_forward():
+@pytest.mark.parametrize("cuts", [(2,), (2, 4)],
+                         ids=["two_stages", "three_stages"])
+def test_stage_split_matches_full_forward(cuts):
+    """Chained stages equal the whole forward; with three stages the middle
+    one neither embeds nor unembeds and starts past layer 0, so its layer
+    range is offset at both ends."""
     cfg = get_config("stablelm-3b").reduced(n_layers=4)
     lbm = _layer_block_map(cfg.n_layers, 5)
     n = len(lbm)
-    model, stages = split_stages(cfg, [(0, 2), (2, n)], lbm)
+    bounds = (0, *cuts, n)
+    model, stages = split_stages(cfg, list(zip(bounds, bounds[1:])), lbm)
     params = model.init(KEY)
     tokens = jnp.arange(2 * 12, dtype=jnp.int32).reshape(2, 12) % cfg.vocab
     full = model.forward(params, {"tokens": tokens})
-    h = stages[0](params, tokens)
-    out = stages[1](params, h)
+    out = tokens
+    for stage in stages:
+        out = stage(params, out)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(full, np.float32), atol=2e-2, rtol=2e-2)
+
+
+def test_middle_stage_bit_identical_to_sliced_scan():
+    """A middle stage, which indexes its layers inside the scan, gives the
+    same bits as scanning over a slice of the stacked layer parameters."""
+    cfg = get_config("stablelm-3b").reduced(n_layers=4)
+    lbm = _layer_block_map(cfg.n_layers, 5)
+    n = len(lbm)
+    model, stages = split_stages(cfg, [(0, 2), (2, 4), (4, n)], lbm)
+    lstart, lend = lbm[2][0], lbm[3][1]
+    assert 0 < lstart < lend < cfg.n_layers
+    params = model.init(KEY)
+    tokens = jnp.arange(2 * 12, dtype=jnp.int32).reshape(2, 12) % cfg.vocab
+    h = jax.jit(stages[0])(params, tokens)
+
+    def sliced_scan(params, x):
+        B, S, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+
+        def body(x, lp):
+            x, _ = tfm.layer_full(cfg, NO_SHARDING, lp, x, positions)
+            return x, None
+
+        lslice = jax.tree.map(lambda a: a[lstart:lend], params["layers"])
+        return jax.lax.scan(body, x, lslice)[0]
+
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(stages[1])(params, h), np.float32),
+        np.asarray(jax.jit(sliced_scan)(params, h), np.float32))
 
 
 def test_stage_functions_named_by_block_range():
