@@ -70,8 +70,8 @@ def main() -> int:
     undo = plant(args.fault) if args.fault else None
     try:
         for seed in args.seeds:
-            r = harness.run_cell(args.workload, seed, args.seconds, False,
-                                 time.perf_counter(), control=args.control)
+            r, _ = harness.run_cell(args.workload, seed, args.seconds, False,
+                                    time.perf_counter(), control=args.control)
             print(json.dumps({"seed": seed, "control": args.control,
                               "fault": args.fault, **r}), flush=True)
     finally:

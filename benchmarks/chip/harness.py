@@ -1,22 +1,28 @@
 """One run of one benchmark cell on the chip, as `run.py` drives it.
 
 A cell names a configuration file (`configs/<config>.json`) and a traffic
-mix (`traffic/<mix>.json`); metrics are readers under `metrics/`, found by
-the names in BENCHMARK.json.  A run:
+mix (`traffic/<mix>.json`); the file's model family (`families/<family>.py`)
+gives the widths to check, the plain reference and the costs; metrics are
+readers under `metrics/`, found by the names in BENCHMARK.json.  A run:
 
 1. checks that JAX sees a TPU with as many chips as the cell asks for;
-2. builds a `Session` from the configuration file: the published widths,
+2. builds a `Session` from the configuration file: the published widths
+   (every width of the family's `KEYS`, checked against the program),
    the pinned plan, the SLO, the program's weights from the seed, and a
    seeded token stream (`Capture.token_fn`) in place of the all-ones default;
 3. deploys (`deploy(mode="real")`, measured feedback: warm compile of every
    batch bucket, then calibration) and serves a warm-up trace of its own;
    everything up to here is `setup_s`;
 4. serves the window: round(rate x seconds) arrivals of the mix, offset
-   past the warm-up on the session's one clock.  Nothing may compile here;
+   past the warm-up on the session's one clock.  Nothing may compile here.
+   At its start the measured-feedback counters are taken and each stage's
+   scale peak reset; after it their deltas are kept (`ctx["feedback"]`);
 5. reads the metrics (with `--trace 1` under the JAX profiler, and then the
-   per-layer metrics instead of the end-to-end ones);
-6. frees the program and compares what the window served against the plain
-   reference (`reference.py`): a seeded reservoir of the window's batches
+   per-layer metrics instead of the end-to-end ones: the trace gives
+   `ctx["trace"]`, with every device op's seconds and calls, and
+   `ctx["spans"]`, the program's host spans);
+6. frees the program and compares what the window served against the
+   family's plain reference: a seeded reservoir of the window's batches
    keeps each one's tokens and the top-1 token the program served at every
    position, and the reference scores those tokens;
 7. prints the checks on stderr and one JSON line on stdout.
@@ -24,6 +30,7 @@ the names in BENCHMARK.json.  A run:
 
 from __future__ import annotations
 
+import dataclasses
 import gc
 import hashlib
 import importlib.util
@@ -38,7 +45,9 @@ from pathlib import Path
 import numpy as np
 
 import costs
+import families
 import reference
+import span_reduce
 import trace_reduce
 import traffic
 
@@ -219,34 +228,39 @@ class Capture:
 
 # ----------------------------------------------------------------- session
 def model_overrides(conf: dict) -> dict | None:
-    """`ModelSpec.reduced` overrides that give the program the file's widths,
-    every width restated (`ModelConfig.reduced` shrinks what is not)."""
+    """`ModelSpec.reduced` overrides that give the program the file's widths:
+    each width of the family's `KEYS` that `ModelConfig` has a field for is
+    restated (`ModelConfig.reduced` shrinks what is not); a width read
+    through a property is set by the field its file key names.  None where
+    the program's own configuration already has every width."""
     from repro.configs import get_config
 
-    m, base = conf["model"], get_config(conf["arch"])
-    keys = ("n_layers", "d_model", "n_heads", "kv_heads", "d_ff", "vocab")
-    if all(getattr(base, k) == m[k] for k in keys) and base.hd == m["head_dim"]:
+    keys, m = families.of(conf).KEYS, conf["model"]
+    missing = sorted(set(keys) - set(m))
+    if missing:
+        raise ValueError(f"{conf['name']}: the file restates no {missing}; "
+                         "its family's KEYS name every width to restate")
+    base = get_config(conf["arch"])
+    if all(getattr(base, attr) == m[key] for key, attr in keys.items()):
         return None
-    return {**{k: m[k] for k in keys}, "head_dim": m["head_dim"]}
+    fields = {f.name for f in dataclasses.fields(base)}
+    return {attr if attr in fields else key: m[key]
+            for key, attr in keys.items() if attr in fields or key in fields}
 
 
 def program_model(conf: dict):
-    """The program's ModelConfig for the file, checked against its widths."""
+    """The program's ModelConfig for the file, checked against its widths
+    and its family's structure."""
     from repro.configs import get_config
 
-    m = conf["model"]
-    cfg = get_config(conf["arch"])
+    fam, m = families.of(conf), conf["model"]
     over = model_overrides(conf)
+    cfg = get_config(conf["arch"])
     if over:
         cfg = cfg.reduced(**over)
-    got = {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
-           "n_heads": cfg.n_heads, "kv_heads": cfg.kv_heads,
-           "head_dim": cfg.hd, "d_ff": cfg.d_ff, "vocab": cfg.vocab,
-           "padded_vocab": cfg.padded_vocab, "rope_theta": cfg.rope_theta,
-           "norm_eps": cfg.norm_eps}
-    want = {k: m[k] for k in got}
-    if got != want or cfg.family != "dense" or cfg.qkv_bias or cfg.qk_norm \
-            or cfg.tie_embeddings:
+    got = {key: getattr(cfg, attr) for key, attr in fam.KEYS.items()}
+    want = {key: m[key] for key in fam.KEYS}
+    if got != want or not fam.check(cfg):
         raise ValueError(f"program's {conf['arch']} is {got}, file says {want}")
     return cfg
 
@@ -332,6 +346,53 @@ def feedback_state(session) -> dict:
                 "expiry_drops", "sched_drops")}}
 
 
+def feedback_counters(session) -> list[dict] | None:
+    """Each plan stage's measured-feedback counters: the sum and number of
+    the walls `FeedbackController.observe` received and the highest scale it
+    set since `reset_peaks`; None where the program keeps none.  Reading
+    them changes no state of the program."""
+    dp = session.dataplane
+    fb = dp.fb
+    if fb is None or not hasattr(fb, "scale_peak"):
+        return None
+    out = []
+    for p in dp.rt.pipelines:
+        for si, sp in enumerate(dp.rt.plan.pipelines[p.pipeline_id].stages):
+            key = (p.pipeline_id, si)
+            out.append({"pipeline": p.pipeline_id, "stage": si,
+                        "blocks": [sp.block_start, sp.block_end],
+                        "measured_wall_s": fb.measured_wall_s.get(key, 0.0),
+                        "observed": fb.observed.get(key, 0),
+                        "scale_peak": fb.scale_peak.get(key)})
+    return out
+
+
+def window_feedback(before: list[dict], after: list[dict]) -> list[dict]:
+    """The window's counters: `after`'s, less `before`'s sums and counts."""
+    return [{**a, "measured_wall_s": a["measured_wall_s"] - b["measured_wall_s"],
+             "observed": a["observed"] - b["observed"]}
+            for b, a in zip(before, after)]
+
+
+def spans_line(ctx: dict) -> str:
+    """Per-span seconds and counts, device idle by span, and each stage's
+    measured wall against its device time."""
+    sp = ctx["spans"]
+    spans = " ".join(f"{k}={v['s']:.6f}s/{v['n']}"
+                     for k, v in sp["spans"].items())
+    idle = " ".join(f"{k}={v:.6f}s" for k, v in sorted(
+        sp["idle_by_span"].items(), key=lambda kv: -kv[1]))
+    stages = []
+    for s in ctx["feedback"] or ():
+        name = "jit_stage_b{}_{}".format(*s["blocks"])
+        dev = sp["modules"].get(name, {"s": 0.0, "n": 0})
+        stages.append(f"{name}: wall {s['measured_wall_s']:.6f}s/"
+                      f"{s['observed']} device {dev['s']:.6f}s/{dev['n']} "
+                      f"scale peak {s['scale_peak']}")
+    return (f"[spans] {spans}; idle {sp['idle_s']:.6f}s: {idle}; "
+            f"stages {'; '.join(stages)}")
+
+
 # -------------------------------------------------------------- comparison
 def gap_stats(gaps: np.ndarray) -> dict:
     """Readings of the top-1 gaps, one row per request and one column per
@@ -342,22 +403,24 @@ def gap_stats(gaps: np.ndarray) -> dict:
             "top1_disagree": float(np.mean(gaps > 0))}
 
 
-def compare(samples, m: dict, seed: int, control: str | None = None) -> dict:
-    """Top-1 gaps of the served tokens under the float32 reference.
+def compare(samples, fam, m: dict, seed: int,
+            control: str | None = None) -> dict:
+    """Top-1 gaps of the served tokens under the family `fam`'s float32
+    reference.
 
     With `control` ("bf16", "fp8") the reference computed in that precision
     takes the program's place: its top-1 tokens are scored instead of the
     served ones, at the same requests and positions."""
     import jax.numpy as jnp
 
-    w = reference.weights(m, derive(seed, "weights"))
+    w = fam.weights(m, derive(seed, "weights"))
     gaps = []
     for n, toks, top1 in samples:
         rows = jnp.asarray(toks[:n])
-        ref = reference.logits(w, m, rows)
+        ref = fam.logits(w, m, rows)
         if control:
-            chosen = jnp.argmax(reference.logits(w, m, rows,
-                                                 precision=control), axis=-1)
+            chosen = jnp.argmax(fam.logits(w, m, rows, precision=control),
+                                axis=-1)
         else:
             chosen = jnp.asarray(top1[:n])
         gaps.append(np.asarray(reference.top1_gaps(ref, chosen)))
@@ -384,14 +447,19 @@ def window_latencies_ms(ctx: dict) -> list[float]:
 
 def run_cell(cell: str, seed: int, seconds: float, trace: bool, t0: float,
              *, conf: dict | None = None, require_chip: bool = True,
-             control: str | None = None, log=sys.stderr) -> dict:
-    """One run of `cell`.  `conf` and `require_chip=False` are for tests on
-    the CPU (a toy configuration, no look for a chip); `control` puts the
-    reference in a lower precision in the program's place for the
-    comparison (`compare`), as `control.py` runs it."""
+             control: str | None = None, events_out: str | None = None,
+             log=sys.stderr) -> tuple[dict, dict]:
+    """One run of `cell`: its result line, and the context its metrics were
+    read from.  `conf` and `require_chip=False` are for tests on the CPU (a
+    toy configuration, no look for a chip); `control` puts the reference in
+    a lower precision in the program's place for the comparison (`compare`),
+    as `control.py` runs it; `events_out` also writes a traced window's
+    events (`trace_reduce.read_xplane`) there, the recording tests/data
+    keeps."""
     bench = load_benchmark()
     wl = next(w for w in bench["workloads"] if w["name"] == cell)
     conf = conf or load_config(bench, wl["config"])
+    fam = families.of(conf)
     mix = traffic.load_mix(wl["traffic"])
     device = check_device(wl["chips"]) if require_chip else describe_device()
     enable_compile_cache()
@@ -422,6 +490,9 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, t0: float,
                       len(warm), cfg.name, slo_s)
     n_disp, n_qd = len(tel.dispatches), len(tel.queue_delay_s)
     before = feedback_state(session)
+    if hasattr(session.dataplane.fb, "reset_peaks"):
+        session.dataplane.fb.reset_peaks()
+    counters = feedback_counters(session)
     setup_s = time.perf_counter() - t0
 
     logdir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
@@ -452,26 +523,37 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, t0: float,
           f"setup_s={setup_s:.3f} (start {t_deploy - t0:.3f}, deploy "
           f"{t_warm - t_deploy:.3f}, warm-up {t0 + setup_s - t_warm:.3f})",
           file=log)
+    after = feedback_counters(session)
     stats = jax.devices()[0].memory_stats() or {}
     ctx = {
-        "config": conf, "seconds": seconds, "chips": wl["chips"],
+        "config": conf, "family": fam, "seconds": seconds,
+        "chips": wl["chips"],
         "wall_s": wall_s, "setup_s": setup_s, "requests": window,
         "outcomes": outcomes, "dispatches": dispatches,
         "queue_delay_s": tel.queue_delay_s[n_qd:],
         "plan_batch": conf["plan"]["batch"], "stage_layers": layers,
         "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0)),
         "peak": costs.peaks(device["kind"]) if require_chip else None,
-        "trace": None,
+        "feedback": (window_feedback(counters, after)
+                     if after is not None else None),
+        "trace": None, "spans": None,
     }
     samples = capture.samples()
     del session, capture, tel
     gc.collect()
     if trace:
         try:
-            ctx["trace"] = trace_reduce.summarize(
-                trace_reduce.read_xplane(logdir), wall_s)
+            events = trace_reduce.read_xplane(logdir)
         finally:
             shutil.rmtree(logdir, ignore_errors=True)
+        ctx["trace"] = trace_reduce.summarize(events, wall_s)
+        ctx["spans"] = span_reduce.reduce(events, wall_s)
+        if events_out:
+            Path(events_out).parent.mkdir(parents=True, exist_ok=True)
+            Path(events_out).write_text(json.dumps({"window_s": wall_s,
+                                                    **events}))
+        del events
+        print(spans_line(ctx), file=log)
 
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}
@@ -486,7 +568,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, t0: float,
                                "allows")
         metrics[spec["name"]] = {"value": value, "unit": spec["unit"]}
 
-    got = compare(samples, m, seed, control) if samples else {"rows": 0}
+    got = compare(samples, fam, m, seed, control) if samples else {"rows": 0}
     print(f"[check] compared {got['rows']} served rows of "
           f"{len(samples)} batches: {json.dumps(got)}", file=log)
     limit = conf["check"][CHECKED]["limit"]
@@ -504,4 +586,4 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, t0: float,
     result["checks"] = checks
     for name, c in checks.items():
         print(f"[check] {name} = {c['value']} limit {c['limit']}", file=log)
-    return result
+    return result, ctx

@@ -7,8 +7,10 @@
 Run from the root of a checkout, on a machine whose JAX sees the TPU chips
 the cell asks for (BENCHMARK.json); anywhere else it exits 3 and prints no
 result.  `--trace 1` runs the window under the JAX profiler and reports the
-cell's per-layer metrics instead of its end-to-end ones.  The checks that
-decide `correct` are the last lines on stderr and the last key of the line.
+cell's per-layer metrics instead of its end-to-end ones; with
+`--events-out <file>` it also writes the window's trace events there (the
+recording `tests/data/spans_v5e.json` was cut from).  The checks that decide
+`correct` are the last lines on stderr and the last key of the line.
 """
 
 import time
@@ -29,13 +31,15 @@ def main() -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--events-out", default=None)
     args = ap.parse_args()
 
     import harness
 
     try:
-        result = harness.run_cell(args.workload, args.seed, args.seconds,
-                                  bool(args.trace), T0)
+        result, _ = harness.run_cell(args.workload, args.seed, args.seconds,
+                                     bool(args.trace), T0,
+                                     events_out=args.events_out)
     except harness.NoChip as exc:
         print(f"no result: {exc}", file=sys.stderr)
         return 3

@@ -2,10 +2,11 @@
 events `trace_reduce.read_xplane` returns.
 
 The serving path marks its host work with `jax.profiler.TraceAnnotation`
-spans named `ppipe.*` (SPANS), on the profiler's host plane, whose clock the
-device planes share.  `reduce` gives:
+spans named `ppipe.*` (PREFIX), on the profiler's host plane, whose clock
+the device planes share.  `reduce` gives:
 
-* ``spans``: for each span name, its seconds and its count;
+* ``spans``: for each span name with that prefix, its seconds and its
+  count, so a span the program adds is counted with no change here;
 * ``idle_by_span``: the device's idle seconds, split by the span the host
   was in at each instant, and ``outside spans`` for the rest.  The idle time
   is the window's complement of the union of the device's op intervals, as
@@ -19,15 +20,15 @@ device planes share.  `reduce` gives:
   (`jit_stage_b{i}_{j}`), so each stage of a pinned plan reads apart.
 
 A program that has no spans gives no span names, and all its idle time
-falls outside spans.  The span names are spelled here because the
-benchmark imports nothing of the program.
+falls outside spans.  The prefix is spelled here because the benchmark
+imports nothing of the program.
 """
 
 from __future__ import annotations
 
 from trace_reduce import MODULE_LINE, OPS_LINE, STAGE_MODULE, _module, _union
 
-SPANS = ("ppipe.schedule", "ppipe.tokens", "ppipe.submit", "ppipe.wait")
+PREFIX = "ppipe."
 OUTSIDE = "outside spans"
 
 
@@ -69,11 +70,12 @@ def reduce(events: dict, window_s: float) -> dict:
     """Span seconds and counts, device idle seconds by span, and stage
     module seconds and calls of a traced window of `window_s` seconds."""
     spans = [(s, s + d, name) for name, s, d in events["host"]
-             if name in SPANS]
-    sums = {name: {"s": 0.0, "n": 0} for name in SPANS}
+             if name.startswith(PREFIX)]
+    sums: dict[str, dict] = {}
     for s, e, name in spans:
-        sums[name]["s"] += (e - s) * 1e-9
-        sums[name]["n"] += 1
+        v = sums.setdefault(name, {"s": 0.0, "n": 0})
+        v["s"] += (e - s) * 1e-9
+        v["n"] += 1
     flat = _flat(spans)
     devices = events["devices"]
     idle: dict[str, float] = {}
@@ -98,7 +100,7 @@ def reduce(events: dict, window_s: float) -> dict:
                 m["n"] += 1
     n = max(1, len(devices))
     return {
-        "spans": {k: v for k, v in sums.items() if v["n"]},
+        "spans": sums,
         "idle_by_span": {k: v / n for k, v in idle.items()},
         "idle_s": sum(idle.values()) / n,
         "modules": {k: {"s": v["s"] / n, "n": v["n"] // n}

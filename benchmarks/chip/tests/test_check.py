@@ -18,10 +18,11 @@ SEEDS = (2**31 + 11, 2**31 + 12, 2**31 + 13)
 
 
 def toy_run(conf, seed=2**31 + 1, control=None):
-    return harness.run_cell("stablelm-3b.steady", seed, 2.0, False,
-                            time.perf_counter(), conf=conf,
-                            require_chip=False, control=control,
-                            log=sys.stderr)
+    result, _ = harness.run_cell("stablelm-3b.steady", seed, 2.0, False,
+                                 time.perf_counter(), conf=conf,
+                                 require_chip=False, control=control,
+                                 log=sys.stderr)
+    return result
 
 
 def test_sound_run_is_correct_and_names_its_checks(conf):
@@ -39,15 +40,17 @@ def test_sound_run_is_correct_and_names_its_checks(conf):
 
 
 def test_traced_run_reads_its_per_layer_metrics(conf):
-    """The traced path end to end on the CPU: the profiler runs, the host
-    and plane metrics are read, and the device ones (no TPU plane, no
-    peaks) are left out rather than read as 0."""
-    r = harness.run_cell("stablelm-3b.steady", 2**31 + 2, 2.0, True,
-                         time.perf_counter(), conf=conf, require_chip=False,
-                         log=sys.stderr)
+    """The traced path end to end on the CPU: the profiler runs, the host,
+    span and counter metrics are read, and the device ones (no TPU plane,
+    no peaks, no stage time to pair walls with) are left out rather than
+    read as 0."""
+    r, _ = harness.run_cell("stablelm-3b.steady", 2**31 + 2, 2.0, True,
+                            time.perf_counter(), conf=conf,
+                            require_chip=False, log=sys.stderr)
     assert r["correct"] is True
     assert set(r["metrics"]) == {"batch_fill", "queue_delay_p95_ms",
-                                 "serve_wall_ratio"}
+                                 "serve_wall_ratio", "sched_us_per_dispatch",
+                                 "host_us_per_batch", "lat_scale_max"}
     assert 0 < r["metrics"]["batch_fill"]["value"] <= 1
 
 

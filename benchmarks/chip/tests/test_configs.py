@@ -1,20 +1,16 @@
 """Each configuration file runs the published widths of its source, except
 the keys its `reduced` lists: the program's ModelConfig is built the way a
 run builds it (`ModelSpec.reduced` overrides, which shrink every width not
-restated and force head_dim 32) and compared with the source."""
+restated and force a head width of 32) and compared with the source, key by key
+as the file's family maps them (`PUBLISHED`, and `CONVENTIONS` for a width
+the source leaves out)."""
 
 import json
 
 import pytest
 
+import families
 import harness
-
-# published key -> the program's ModelConfig field (None: not a field)
-KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-        "num_attention_heads": "n_heads", "num_key_value_heads": "kv_heads",
-        "intermediate_size": "d_ff", "vocab_size": "vocab",
-        "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-        "layer_norm_eps": "norm_eps"}
 
 BENCH = harness.load_benchmark()
 FILES = sorted((harness.BENCH_DIR / "configs").glob("*.json"))
@@ -31,14 +27,20 @@ def test_benchmark_entry_matches_its_file(entry):
 def test_program_runs_the_published_widths(path):
     conf = json.loads(path.read_text())
     assert conf["name"] == path.stem
+    fam = families.of(conf)
     cfg = harness.program_model(conf)
     pub = conf["published"]
-    for key, field in KEYS.items():
-        if key not in pub:
+    for key, mkey in fam.PUBLISHED.items():
+        if key in conf["reduced"]:
+            want = conf["reduced"][key]["run"]
+        elif key in pub:
+            want = pub[key]
+        elif key in fam.CONVENTIONS:
+            want = fam.CONVENTIONS[key](pub)
+        else:
             continue
-        want = conf["reduced"][key]["run"] if key in conf["reduced"] else pub[key]
-        assert getattr(cfg, field) == pytest.approx(want), key
-    assert cfg.hd == pub["hidden_size"] // pub["num_attention_heads"]
+        assert conf["model"][mkey] == pytest.approx(want), key
+        assert getattr(cfg, fam.KEYS[mkey]) == pytest.approx(want), key
     assert cfg.dtype.__name__ == conf["dtype"]
 
 

@@ -1,9 +1,12 @@
 import json
+import math
 
 import pytest
 
 import costs
+import families
 import harness
+import reference
 
 
 def _conf(name):
@@ -27,12 +30,19 @@ def _toy():
 def test_request_flops_are_two_flops_per_weight_plus_attention(conf):
     """2 x (layer + head weights) x tokens, plus causal attention; the toy
     has grouped key/value heads, so the count of k and v weights is checked
-    too.  The norms' scales are weights but no matrix product."""
+    too.  The norms' scales are weights but no matrix product.  The layer's
+    weights are the reference's shapes, so the two count one model; the
+    query width is the file's heads times their width (the key the family
+    maps to `ModelConfig.hd`)."""
     m, seq = conf["model"], conf["seq_len"]
+    shapes = reference._shapes(m)
+    layer = sum(math.prod(shapes[k][1:]) for k in reference._LAYER_LEAVES)
+    assert costs.layer_params(m) == layer
     norms = 2 * m["d_model"]
-    weights = (m["n_layers"] * (costs.layer_params(m) - norms)
-               + m["d_model"] * m["vocab"])
-    attn = m["n_layers"] * 4 * m["n_heads"] * m["head_dim"] * seq * (seq + 1) / 2
+    weights = m["n_layers"] * (layer - norms) + m["d_model"] * m["vocab"]
+    head_width = {a: k for k, a in families.of(conf).KEYS.items()}["hd"]
+    q_width = m["n_heads"] * m[head_width]
+    attn = m["n_layers"] * 4 * q_width * seq * (seq + 1) / 2
     assert costs.request_flops(m, seq) == pytest.approx(
         2 * seq * weights + attn, rel=1e-12)
 

@@ -34,6 +34,20 @@ def test_union_gaps_and_stage_programs():
                               ["PjitFunction(stage)", pytest.approx(0.004)]]
 
 
+def test_every_op_is_kept_with_its_calls():
+    """`ops` holds every device op's seconds and calls, averaged over the
+    chips; `device_ops` stays the ten that took most time."""
+    ops = [(f"op.{i}", 2 * i, 1 + i / 10) for i in range(12)]
+    ev = _events(ops=ops + [("op.0", 30, 0.25)], modules=[])
+    ev["devices"]["/device:TPU:1"] = ev["devices"]["/device:TPU:0"]
+    s = trace_reduce.summarize(ev, window_s=0.040)
+    assert set(s["ops"]) == {f"op.{i}" for i in range(12)}
+    assert s["ops"]["op.0"] == {"s": pytest.approx(0.00125), "n": 2}
+    assert s["ops"]["op.11"] == {"s": pytest.approx(0.0021), "n": 1}
+    assert [k for k, _ in s["device_ops"]] == [
+        *(f"op.{i}" for i in range(11, 2, -1)), "op.0"]
+
+
 def test_gap_without_host_activity_is_host_python():
     ev = _events(ops=[("a", 0, 1), ("b", 3, 1)], modules=[])
     assert trace_reduce.summarize(ev, 0.004)["idle_gaps"] == [

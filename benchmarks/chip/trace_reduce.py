@@ -8,7 +8,8 @@ host's TraceMe events.  `summarize` reduces those lists:
   averaged over the chips);
 * stage-program seconds and calls: the "XLA Modules" events whose module
   name starts with `jit_stage`, the serving path's stage programs;
-* the ten device ops that took most time, summed by op name;
+* every device op's seconds and calls, by op name (``ops``), and the ten
+  that took most time (``device_ops``);
 * the ten longest gaps between busy intervals, each named by the host event
   that overlaps it most ("host python" where no host event does).
 
@@ -75,27 +76,31 @@ def summarize(events: dict, window_s: float) -> dict | None:
         return None
     busy_s = stage_s = 0.0
     stage_calls = 0
-    op_time: dict[str, float] = {}
+    ops: dict[str, dict] = {}
     gaps: list[tuple[float, float]] = []
     for lines in devices.values():
-        ops = lines.get(OPS_LINE) or lines.get(MODULE_LINE, [])
-        merged = _union([(s, s + d) for _, s, d in ops])
+        op_events = lines.get(OPS_LINE) or lines.get(MODULE_LINE, [])
+        merged = _union([(s, s + d) for _, s, d in op_events])
         busy_s += sum(e - s for s, e in merged) * 1e-9
         gaps += [(a[1], b[0]) for a, b in zip(merged, merged[1:])]
-        for name, _, d in ops:
-            op_time[name] = op_time.get(name, 0.0) + d * 1e-9
+        for name, _, d in op_events:
+            op = ops.setdefault(name, {"s": 0.0, "n": 0})
+            op["s"] += d * 1e-9
+            op["n"] += 1
         for name, _, d in lines.get(MODULE_LINE, []):
             if _module(name).startswith(STAGE_MODULE):
                 stage_s += d * 1e-9
                 stage_calls += 1
     n = len(devices)
     gaps.sort(key=lambda g: g[0] - g[1])
+    ops = {k: {"s": v["s"] / n, "n": v["n"] // n} for k, v in ops.items()}
     return {
         "busy_s": busy_s / n,
         "window_s": window_s,
         "stage_s": stage_s / n,
         "stage_calls": stage_calls // n,
-        "device_ops": sorted(([k, v / n] for k, v in op_time.items()),
+        "ops": ops,
+        "device_ops": sorted(([k, v["s"]] for k, v in ops.items()),
                              key=lambda kv: -kv[1])[:10],
         "idle_gaps": [[_host_activity(events["host"], s, e), (e - s) * 1e-9]
                       for s, e in gaps[:10]],
